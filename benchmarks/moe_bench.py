@@ -153,6 +153,9 @@ def moe_device_scaling(smoke: bool = False):
     Cells: 1 device (mesh-free baseline), 2/4/8 devices with 2-way "model"
     parallelism (E=4 experts split 2-way: the experts=chips mapping), and
     a 2-stage pipelined cell (depth 4 factors into 2 stages)."""
+    from benchmarks.serve_bench import require_cpu_parent
+
+    require_cpu_parent("moe_device_scaling")
     cells = [(1, 1, 1), (2, 2, 1), (2, 1, 2)] if smoke else \
         [(1, 1, 1), (2, 2, 1), (4, 2, 1), (8, 2, 1), (2, 1, 2)]
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

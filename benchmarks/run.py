@@ -43,6 +43,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="CI-scale serving benchmark (same artifact shape)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import (autotune_bench, cnn_bench, fault_bench, kernel_bench,
                    lm_roofline, moe_bench, paper_figures, serve_bench)

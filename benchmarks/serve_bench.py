@@ -236,6 +236,17 @@ print(json.dumps({
 """
 
 
+def require_cpu_parent(section: str) -> None:
+    """The device-scaling sections start ``JAX_PLATFORMS=cpu`` children:
+    they check the sharding mechanism on forced host devices and time the
+    CPU. Run from a parent on an accelerator, they would file CPU timings
+    beside its numbers, so they refuse."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{section} times forced CPU host devices; it runs only from a "
+            f"CPU parent, not from {jax.default_backend()!r}")
+
+
 def serve_device_scaling(smoke: bool = False):
     """Decode throughput of the mesh-sharded engine per device count.
 
@@ -253,6 +264,7 @@ def serve_device_scaling(smoke: bool = False):
     n=1 / n=drain_steps decode family, i.e. no collective inside the scan
     body), which is what transfers to a real multi-chip deployment.
     """
+    require_cpu_parent("serve_device_scaling")
     cells = [(1, 1), (2, 2)] if smoke else [(1, 1), (2, 2), (4, 2), (8, 2)]
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, PYTHONPATH="src" + os.pathsep + ".",

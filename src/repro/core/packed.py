@@ -99,9 +99,10 @@ class PackedConvWeight:
 
     mat          PackedWeight over the (KH*KW*C, O) im2col matrix — drives
                  the materialized path and the affine correction.
-    fused_planes (KH, bits, O, KW, CW) uint32 — channel-packed planes per
+    fused_planes (KH, bits, KW, CW, O) uint32 — channel-packed planes per
                  kernel row, the layout the fused implicit-im2col kernel
-                 streams one (kh) slab at a time.
+                 streams one (kh) slab at a time (output channels last, on
+                 the kernel's lanes).
     """
 
     mat: PackedWeight
@@ -201,7 +202,7 @@ def shard_packed(pw: PackedWeight | PackedConvWeight, mesh,
             raise ValueError(
                 "PackedConvWeight shards on the bank (output-channel) "
                 "mapping only; split='k' has no conv layout")
-        fused_spec = _guard((None, None, axis, None, None),
+        fused_spec = _guard((None, None, None, None, axis),
                             pw.fused_planes.shape, mesh,
                             label="shard_packed:fused_planes")
         return PackedConvWeight(
@@ -264,15 +265,21 @@ def repack_codes(pw: PackedWeight, codes: jax.Array) -> PackedWeight:
                         col_sums=pw.col_sums, wq=pw.wq, tune=pw.tune)
 
 
+def fused_conv_planes(codes: jax.Array, bits: int) -> jax.Array:
+    """(KH, KW, C, O) conv codes -> the fused kernel's (KH, bits, KW, CW, O)
+    planes: per kernel row, channels packed into words, O last."""
+    planes = bitslice.slice_and_pack(codes.transpose(0, 1, 3, 2), bits)
+    return planes.transpose(1, 0, 2, 4, 3)       # from (bits, KH, KW, O, CW)
+
+
 def repack_conv_codes(pcw: PackedConvWeight, flat_codes: jax.Array
                       ) -> PackedConvWeight:
     """Conv analog of :func:`repack_codes`: new (KH*KW*C, O) im2col codes,
     both lowering layouts rebuilt so they describe the same device state."""
-    kh, kw, c, o = pcw.kernel_shape
-    wt = flat_codes.reshape(kh, kw, c, o).transpose(0, 3, 1, 2)
-    fused = bitslice.slice_and_pack(wt, pcw.bits).transpose(1, 0, 2, 3, 4)
     return PackedConvWeight(mat=repack_codes(pcw.mat, flat_codes),
-                            fused_planes=fused,
+                            fused_planes=fused_conv_planes(
+                                flat_codes.reshape(pcw.kernel_shape),
+                                pcw.bits),
                             kernel_shape=pcw.kernel_shape, tune=pcw.tune)
 
 
@@ -288,9 +295,6 @@ def prepack_conv(w: jax.Array, w_bits: int) -> PackedConvWeight:
         col_sums=flat.sum(0).astype(jnp.int32),
         wq=wq,
     )
-    # Fused layout: per kernel row kh, O-major, channels packed into words.
-    wt = codes.transpose(0, 3, 1, 2)                     # (KH, O, KW, C)
-    fused = bitslice.slice_and_pack(wt, w_bits)          # (bits, KH, O, KW, CW)
-    fused = fused.transpose(1, 0, 2, 3, 4)               # (KH, bits, O, KW, CW)
-    return PackedConvWeight(mat=mat, fused_planes=fused,
+    return PackedConvWeight(mat=mat, fused_planes=fused_conv_planes(codes,
+                                                                    w_bits),
                             kernel_shape=(kh, kw, c, o))

@@ -241,10 +241,13 @@ def pim_conv2d(
                        ((0, 0), (padding, padding), (padding, padding),
                         (0, 0)))
         wsum = w.mat.codes.reshape(kh, kw, c, o).sum(2)          # (KH, KW, O)
+        # HIGHEST: the integer code sums (< 2^24) must stay exact — a TPU
+        # conv at default precision rounds them to bf16.
         sw = jax.lax.conv_general_dilated(
             mask, wsum[:, :, None, :].astype(jnp.float32),
             (stride, stride), "VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))          # (1,OH,OW,O)
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST)                 # (1,OH,OW,O)
         k_real = c * jax.lax.reduce_window(
             mask[..., 0], 0.0, jax.lax.add, (1, kh, kw),
             (1, stride, stride), "VALID")[..., None]             # (1,OH,OW,1)

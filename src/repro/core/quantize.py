@@ -50,9 +50,17 @@ def calibrate_minmax(x: jax.Array, bits: int, axis=None) -> QuantParams:
 
 
 def quantize(x: jax.Array, qp: QuantParams) -> jax.Array:
-    """Eq. 2 forward: float -> unsigned integer codes in [0, 2^bits)."""
+    """Eq. 2 forward: float -> unsigned integer codes in [0, 2^bits).
+
+    The codes are materialized (``optimization_barrier``): every consumer —
+    the Eq. 1 product, the ``Sa`` marginal — reads the same array. Fused
+    into each consumer instead, the rounding would be recomputed in
+    different fusion contexts, which on TPU need not agree to the last
+    bit, and the backends would stop agreeing bit for bit.
+    """
     q = jnp.round((x.astype(jnp.float32) - qp.qmin) / qp.scale)
-    return jnp.clip(q, 0.0, float(2**qp.bits - 1)).astype(jnp.int32)
+    return jax.lax.optimization_barrier(
+        jnp.clip(q, 0.0, float(2**qp.bits - 1)).astype(jnp.int32))
 
 
 def dequantize(q: jax.Array, qp: QuantParams) -> jax.Array:
@@ -111,8 +119,13 @@ def affine_correction(
     a spatially-padded convolution treats padded taps as contributing
     *exactly zero*, so near borders the effective weight-code sum and the
     effective contraction length shrink per patch (see ``pim_conv2d``).
+
+    ``prod`` is materialized first, so the float epilogue fuses the same
+    way whichever backend produced P (an XLA dot would otherwise take it
+    into its output fusion and a Pallas kernel could not): the backends
+    then agree bit for bit, as the autotuner's contract requires.
     """
-    p = prod.astype(jnp.float32)
+    p = jax.lax.optimization_barrier(prod).astype(jnp.float32)
     return (
         aq.scale * wq.scale * p
         + aq.scale * wq.qmin * sa.astype(jnp.float32)

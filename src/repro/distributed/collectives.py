@@ -60,37 +60,6 @@ def make_grad_compressor(cfg: CompressionConfig):
     return compress
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check_rep: bool = True):
-    """jax.shard_map when available (>=0.6), else the experimental one —
-    the same compat split `distributed.pipeline` uses.
-
-    ``check_rep=False`` disables the replication/vma checker — required for
-    bodies containing ``pallas_call`` (no replication rule registered)."""
-    if hasattr(jax, "shard_map"):
-        # The checker kwarg was renamed check_rep -> check_vma across jax
-        # versions; try both spellings. When neither is accepted, fall back
-        # to the default only if the caller did not need the checker OFF —
-        # bodies like pallas_call have no replication rule, and tracing
-        # them with checking enabled fails with an opaque error.
-        for kw in ({"check_vma": check_rep}, {"check_rep": check_rep}):
-            try:
-                return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-        if check_rep:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-        raise RuntimeError(
-            "this jax version's shard_map accepts neither check_vma nor "
-            "check_rep; cannot disable the replication checker this body "
-            "requires")
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
-
-
 def exact_psum(x: jax.Array, axis_name: str) -> jax.Array:
     """Lossless cross-shard partial-sum reduction — the paper's
     cross-subarray accumulation as a mesh collective.

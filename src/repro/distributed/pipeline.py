@@ -21,17 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax.lax.pvary landed after 0.4.x; the shard_map version split lives in
-# collectives.shard_map_compat. The replication checker is disabled here:
-# the ppermute/psum pattern below is device-varying by design.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    from .collectives import shard_map_compat
-
-    return shard_map_compat(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                            check_rep=False)
+    # The replication checker is off: the ppermute/psum pattern below is
+    # device-varying by design.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pipeline_forward(stage_params, x_microbatches, stage_fn, mesh,
@@ -55,8 +50,8 @@ def pipeline_forward(stage_params, x_microbatches, stage_fn, mesh,
         mb_shape = xs.shape[1:]
         # carries become device-varying inside the loop (ppermute/axis_index)
         # — mark the initial values as varying for shard_map's vma typing.
-        outputs = _pvary(jnp.zeros_like(xs), (stage_axis,))
-        carry_in = _pvary(jnp.zeros(mb_shape, xs.dtype), (stage_axis,))
+        outputs = jax.lax.pvary(jnp.zeros_like(xs), (stage_axis,))
+        carry_in = jax.lax.pvary(jnp.zeros(mb_shape, xs.dtype), (stage_axis,))
 
         def tick(t, state):
             outputs, carry_in = state
@@ -160,9 +155,9 @@ def pipeline_decode_step(params, cfg, tokens, state, *, mesh,
         # sp_l leaves (R/S, ...), ss_l leaves (R/S, B, ...): this stage's
         # contiguous run of unit repetitions and their decode state.
         stage_id = jax.lax.axis_index(stage_axis)
-        outputs = _pvary(jnp.zeros_like(xm), (stage_axis,))
-        carry = _pvary(jnp.zeros((mb, 1, d), x.dtype), (stage_axis,))
-        aux0 = jax.tree.map(lambda v: _pvary(v, (stage_axis,)), _zero_aux())
+        outputs = jax.lax.pvary(jnp.zeros_like(xm), (stage_axis,))
+        carry = jax.lax.pvary(jnp.zeros((mb, 1, d), x.dtype), (stage_axis,))
+        aux0 = jax.tree.map(lambda v: jax.lax.pvary(v, (stage_axis,)), _zero_aux())
 
         def unit_scan(x_in, ss_slice, qp, ci):
             def unit_fn(xc, per_rep):

@@ -540,8 +540,8 @@ def _serve_cnn_param_spec(path, leaf, mesh: Mesh) -> P:
             spec = (None, "model", None)
         elif field == "col_sums":       # (O,)
             spec = ("model",)
-        elif field == "fused_planes":   # (KH, bits, O, KW, CW)
-            spec = (None, None, "model", None, None)
+        elif field == "fused_planes":   # (KH, bits, KW, CW, O)
+            spec = (None, None, None, None, "model")
         else:                           # QuantParams scale/qmin
             return P(*(None,) * leaf.ndim)
     elif name in ("b", "gamma", "beta", "mean", "var") and leaf.ndim == 1:

@@ -30,14 +30,17 @@ Two entry points:
                              prepacked (see ``repro.core.packed`` — the
                              paper's program-subarrays-once step).
 
-The (bm, chunk, bkw) broadcast intermediate is tiled by an inner fori_loop
-over output-column chunks of 128 lanes to bound VREG/VMEM pressure
-(`_OC` below); tiles whose ``bn`` is not a multiple of 128 fall back to an
-unchunked accumulation (previously they silently computed only the first
-``bn // 128`` lane groups — see tests/test_kernels.py regression). The MXU
-is idle in these kernels by design — Eq. 1 is a pure VPU bit-op pipeline.
-See ``mxu_plane`` in :mod:`repro.core.bitserial` for the systolic
-alternative, and DESIGN.md §2 for the trade-off experiment.
+Both kernels accumulate Eq. 1 one packed K word at a time on 2-D
+(bm, bn) tiles, output columns on lanes: word ``kk`` of the activation
+plane is a (bm, 1) column broadcast across lanes, word ``kk`` of the weight
+plane a (1, bn) row broadcast across sublanes, and their AND + popcount
+adds into the tile. The weight block arrives (bn, bkw) and is transposed
+once per grid step into a VMEM scratch; the (plane, plane) pairs run in a
+``fori_loop`` so compile time does not grow with ``a_bits * w_bits``. The
+MXU is idle in these kernels apart from the activation pack (see
+:mod:`.bitplane_pack`) — Eq. 1 is a VPU bit-op pipeline. See ``mxu_plane``
+in :mod:`repro.core.bitserial` for the systolic alternative, and DESIGN.md
+§2 for the trade-off experiment.
 """
 from __future__ import annotations
 
@@ -46,66 +49,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# Output-column chunk for the inner loop: one lane group.
-_OC = 128
-
-
-def _accumulate(planes, w_ref, o_ref, *, a_bits: int, w_bits: int, bm: int,
-                bn: int, bkw: int):
-    """Shared Eq. 1 accumulation: planes[n] is the (bm, bkw) uint32 plane."""
-    if bn % _OC:
-        # Narrow / non-lane-multiple outputs: no column chunking.
-        acc = jnp.zeros((bm, bn), jnp.int32)
-        for n in range(a_bits):
-            a = planes[n]
-            for m in range(w_bits):
-                cnt = jax.lax.population_count(a[:, None, :] & w_ref[m][None, :, :])
-                acc += cnt.sum(-1).astype(jnp.int32) << (n + m)
-    else:
-        def oc_body(c, acc):
-            # acc: (bm, bn) int32. Process output columns [c*_OC, (c+1)*_OC).
-            partial = jnp.zeros((bm, _OC), jnp.int32)
-            for n in range(a_bits):          # static unroll: plane pairs
-                a = planes[n]                # (bm, bkw) uint32
-                for m in range(w_bits):
-                    w = jax.lax.dynamic_slice(w_ref[m], (c * _OC, 0), (_OC, bkw))
-                    # sense-amp AND + per-column bitcount, 32 cells per lane
-                    cnt = jax.lax.population_count(a[:, None, :] & w[None, :, :])
-                    partial += cnt.sum(-1).astype(jnp.int32) << (n + m)
-            return jax.lax.dynamic_update_slice(acc, partial, (0, c * _OC))
-
-        acc = jax.lax.fori_loop(0, bn // _OC, oc_body,
-                                jnp.zeros((bm, bn), jnp.int32))
-    o_ref[...] += acc
+from .bitplane_pack import pack_tile
 
 
-def _kernel(a_ref, w_ref, o_ref, *, a_bits: int, w_bits: int, bm: int, bn: int,
-            bkw: int):
-    # Zero the accumulator tile on the first K step (grid axis 2 innermost).
+def _accumulate(planes_ref, w_ref, o_ref, wt_ref, *, a_bits: int,
+                w_bits: int):
+    """Eq. 1 accumulation into ``o_ref`` (bm, bn); the packed-plane kernel.
+
+    planes_ref (a_bits, bm, bkw) activation planes; w_ref (w_bits, bn, bkw)
+    weight planes; wt_ref (w_bits, bkw, bn) scratch for their transpose.
+    """
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    planes = [a_ref[n] for n in range(a_bits)]
-    _accumulate(planes, w_ref, o_ref, a_bits=a_bits, w_bits=w_bits, bm=bm,
-                bn=bn, bkw=bkw)
+    bm, bn = o_ref.shape
+    bkw = planes_ref.shape[-1]
+    for m in range(w_bits):
+        wt_ref[m] = w_ref[m].T
+
+    def pair(t, acc):
+        n, m = t // w_bits, t % w_bits
+        a = planes_ref[n]                         # (bm, bkw)
+        w = wt_ref[m]                             # (bkw, bn)
+        cnt = jnp.zeros((bm, bn), jnp.int32)
+        for kk in range(bkw):                     # static unroll: K words
+            # sense-amp AND + per-column bitcount, 32 cells per word
+            x = (jnp.broadcast_to(a[:, kk:kk + 1], (bm, bn))
+                 & jnp.broadcast_to(w[kk:kk + 1, :], (bm, bn)))
+            cnt += jax.lax.population_count(x).astype(jnp.int32)
+        return acc + (cnt << (n + m))
+
+    o_ref[...] += jax.lax.fori_loop(0, a_bits * w_bits, pair,
+                                    jnp.zeros((bm, bn), jnp.int32))
 
 
-def _fused_kernel(qa_ref, w_ref, o_ref, *, a_bits: int, w_bits: int, bm: int,
-                  bn: int, bkw: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
+def _fused_kernel(qa_ref, w_ref, o_ref, planes_ref, wt_ref, *, a_bits: int,
+                  w_bits: int):
     # Bit-slice + lane-pack the activation K tile in VMEM: the packed planes
     # are kernel-local, never written to HBM (vs. the 3-launch pipeline).
-    q = qa_ref[...].astype(jnp.uint32).reshape(bm, bkw, 32)
-    lane_w = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))[None, None, :]
-    planes = [(((q >> jnp.uint32(n)) & jnp.uint32(1)) * lane_w).sum(
-        -1, dtype=jnp.uint32) for n in range(a_bits)]
-    _accumulate(planes, w_ref, o_ref, a_bits=a_bits, w_bits=w_bits, bm=bm,
-                bn=bn, bkw=bkw)
+    for n, plane in enumerate(pack_tile(qa_ref[...].astype(jnp.int32),
+                                        a_bits)):
+        planes_ref[n] = plane
+    _accumulate(planes_ref, w_ref, o_ref, wt_ref, a_bits=a_bits,
+                w_bits=w_bits)
 
 
 def _check_blocks(m, n, kw, bm, bn, bkw):
@@ -136,19 +125,16 @@ def bitserial_matmul_packed(
     bkw = min(bkw, kw)
     _check_blocks(m, n, kw, bm, bn, bkw)
 
-    grid = (m // bm, n // bn, kw // bkw)
-    kern = functools.partial(
-        _kernel, a_bits=a_bits, w_bits=w_bits, bm=bm, bn=bn, bkw=bkw
-    )
     return pl.pallas_call(
-        kern,
-        grid=grid,
+        functools.partial(_accumulate, a_bits=a_bits, w_bits=w_bits),
+        grid=(m // bm, n // bn, kw // bkw),
         in_specs=[
             pl.BlockSpec((a_bits, bm, bkw), lambda i, j, k: (0, i, k)),
             pl.BlockSpec((w_bits, bn, bkw), lambda i, j, k: (0, j, k)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((w_bits, bkw, bn), jnp.uint32)],
         interpret=interpret,
     )(pa, pw)
 
@@ -177,19 +163,17 @@ def bitserial_matmul_fused(
     bkw = min(bkw, kw)
     _check_blocks(m, n, kw, bm, bn, bkw)
 
-    grid = (m // bm, n // bn, kw // bkw)
-    kern = functools.partial(
-        _fused_kernel, a_bits=a_bits, w_bits=w_bits, bm=bm, bn=bn, bkw=bkw
-    )
     return pl.pallas_call(
-        kern,
-        grid=grid,
+        functools.partial(_fused_kernel, a_bits=a_bits, w_bits=w_bits),
+        grid=(m // bm, n // bn, kw // bkw),
         in_specs=[
             pl.BlockSpec((bm, bkw * 32), lambda i, j, k: (i, k)),
             pl.BlockSpec((w_bits, bn, bkw), lambda i, j, k: (0, j, k)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((a_bits, bm, bkw), jnp.uint32),
+                        pltpu.VMEM((w_bits, bkw, bn), jnp.uint32)],
         interpret=interpret,
     )(qa, pw)
 
@@ -222,7 +206,7 @@ def bitserial_matmul_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.collectives import exact_psum, shard_map_compat
+    from repro.distributed.collectives import exact_psum
 
     m, k = qa.shape
     _, n, kw = pw.shape
@@ -238,9 +222,9 @@ def bitserial_matmul_sharded(
                                    bm=bm, bn=bn, bkw=bkw, interpret=interpret)
         return exact_psum(p, axis)
 
-    return shard_map_compat(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(None, axis), P(None, None, axis)),
         out_specs=P(None, None),
-        check_rep=False,   # pallas_call has no replication rule
+        check_vma=False,   # pallas_call has no replication rule
     )(qa, pw)

@@ -7,13 +7,22 @@ parallelism.
 
 Functions, not module constants: importing this module must never touch
 jax device state (the dry-run sets XLA_FLAGS before any jax init).
+
+Every mesh has ``Auto`` axes: the sharding rules place arrays with
+``with_sharding_constraint`` and leave the rest to GSPMD, which
+``jax.make_mesh``'s default ``Explicit`` axes refuse.
 """
 from __future__ import annotations
 
-import jax
-
-
 import math
+
+import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple, devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,7 +35,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, found {len(devices)}; "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_test_mesh(n_devices: int | None = None):
@@ -37,7 +46,8 @@ def make_test_mesh(n_devices: int | None = None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"),
+                      jax.devices()[:n])
 
 
 def make_serve_mesh(model_par: int = 1, n_devices: int | None = None):
@@ -65,5 +75,5 @@ def make_serve_mesh(model_par: int = 1, n_devices: int | None = None):
             "jax import")
     if model_par < 1 or n % model_par:
         raise ValueError(f"model_par={model_par} must divide n_devices={n}")
-    return jax.make_mesh((n // model_par, model_par), ("data", "model"),
-                         devices=devices[:n])
+    return _auto_mesh((n // model_par, model_par), ("data", "model"),
+                      devices[:n])

@@ -21,8 +21,8 @@ import* (XLA reads the flag at backend init):
       python -m repro.launch.serve --arch qwen3-0.6b --reduced \
       --model-par 2 --max-batch 8
 
-With a single device (and the default ``--model-par 1``) the engines run
-exactly as before — mesh-free.
+Without ``--model-par`` (or ``--pipeline-stages``) the engines run
+mesh-free on the first device, however many the host has.
 
 ``--gateway`` puts the asyncio overload gateway (DESIGN.md §8) in front of
 the LM engine: Poisson arrivals at ``--rate`` req/s into bounded per-tenant
@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serve_mesh
 from repro.models.lm import init as model_init
 from repro.models.lm.model import cast_params
@@ -209,6 +210,7 @@ def main():
                     help="JSON tuning-cache file persisting autotune "
                          "decisions across launches (default: in-memory)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.pipeline_stages > 1:
@@ -218,7 +220,7 @@ def main():
         print(f"pipelined decode over {args.pipeline_stages} stage(s), "
               f"{args.pipeline_microbatches or args.pipeline_stages} "
               "microbatch(es)")
-    elif len(jax.devices()) > 1 or args.model_par > 1:
+    elif args.model_par > 1:
         mesh = make_serve_mesh(args.model_par)
         print(f"serving on mesh {dict(mesh.shape)} "
               f"({len(mesh.devices.ravel())} devices)")

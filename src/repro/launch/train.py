@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed import sharding as sh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_test_mesh
 from repro.models.lm import init as model_init
 from repro.models.lm.model import cast_params
@@ -88,6 +89,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, mesh, params, opt_state, step, source, put = build(
         args.arch, args.reduced, args.batch, args.seq, args.steps, args.lr,

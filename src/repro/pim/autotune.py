@@ -102,10 +102,9 @@ _GEO = Geometry()
 def device_kind() -> str:
     import jax
 
-    try:
-        return jax.devices()[0].device_kind.replace(" ", "-").lower()
-    except Exception:  # pragma: no cover - backend init failure
-        return "unknown"
+    # A failed backend start raises here: tuning decisions are keyed by
+    # device, so a guessed kind would file them under the wrong one.
+    return jax.devices()[0].device_kind.replace(" ", "-").lower()
 
 
 @functools.lru_cache(maxsize=1)
@@ -202,16 +201,16 @@ def _price(spec: GemmSpec, ab: int, wb: int) -> float:
 
 def _tile_factor(m: int, k: int, n: int, a_bits: int, w_bits: int,
                  d: TuneDecision) -> float:
-    """Pallas tile quality multiplier: grid-step overhead, the bn%128
-    unchunked-fallback path, and VMEM overflow. Purely relative — it orders
-    tile candidates of one shape, nothing else."""
+    """Pallas tile quality multiplier: grid-step overhead, output tiles
+    that fill only part of a lane group, and VMEM overflow. Purely
+    relative — it orders tile candidates of one shape, nothing else."""
     kw = max(1, -(-k // 32))
     bm, bn, bkw = d.bm or m, d.bn or n, d.bkw or kw
     steps = (math.ceil(m / bm) * math.ceil(n / bn) * math.ceil(kw / bkw))
     ws = (a_bits * bm * bkw + w_bits * bn * bkw + bm * bn) * 4
     f = 1.0 + 0.002 * (steps - 1)
     if bn % 128:
-        f *= 1.5          # loses the _OC lane-chunk path in the kernel
+        f *= 1.5          # a partial lane group idles part of every vreg
     if ws > _VMEM_BUDGET:
         f *= 4.0          # working set spills the per-step VMEM budget
     return f

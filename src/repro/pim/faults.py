@@ -59,7 +59,8 @@ import jax.numpy as jnp
 
 from repro.core import bitslice
 from repro.core.packed import (PackedConvWeight, PackedWeight,
-                               repack_codes, repack_conv_codes)
+                               fused_conv_planes, repack_codes,
+                               repack_conv_codes)
 
 # Key-derivation tags: one disjoint fold_in stream per fault mechanism.
 _TAG_WRITE, _TAG_RETAIN, _TAG_DISTURB = 0x57, 0x52, 0x44
@@ -411,6 +412,4 @@ def disturb_fused_planes(fused: jax.Array, kernel_shape) -> jax.Array:
     kh, kw, c, o = kernel_shape
     bits = fused.shape[1]
     field = transient_flip_field((kh * kw * c, o), bits, cfg, _site_key())
-    ft = field.reshape(kh, kw, c, o).transpose(0, 3, 1, 2)   # (KH, O, KW, C)
-    mask = bitslice.slice_and_pack(ft, bits).transpose(1, 0, 2, 3, 4)
-    return fused ^ mask
+    return fused ^ fused_conv_planes(field.reshape(kh, kw, c, o), bits)

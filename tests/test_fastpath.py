@@ -179,17 +179,20 @@ def test_fused_conv_stride2_nonsquare_odd_width(bits, stride, padding):
 def test_fused_conv_odd_o_pads_not_degenerates():
     """Regression: prime O used to shrink the output block to bo=1 (an
     O-sized grid of tiny kernels). Now O pads up to the requested block and
-    the result is sliced — same bits, bounded grid."""
+    the result is sliced — same bits, bounded grid. O sits on lanes, so a
+    block is all of O or a multiple of 128."""
     from repro.kernels.conv2d_fused import _pad_o_blocks
 
     # prime O with the default block: one padded 128-block step, not 131.
     assert _pad_o_blocks(131, 128) == (128, 125)
-    assert _pad_o_blocks(67, 32) == (32, 29)     # grid 3, not 67
+    assert _pad_o_blocks(67, 32) == (67, 0)      # one lane group: one tile
     assert _pad_o_blocks(65, 128) == (65, 0)     # O < block: single tile
     assert _pad_o_blocks(128, 128) == (128, 0)   # exact fit: no padding
-    for o, bo in [(131, 128), (67, 32), (193, 128)]:
+    assert _pad_o_blocks(300, 32) == (128, 84)   # block rounds up to lanes
+    for o, bo in [(131, 128), (67, 32), (193, 128), (300, 32)]:
         b, pad = _pad_o_blocks(o, bo)
         assert (o + pad) % b == 0
+        assert b == o + pad or b % 128 == 0       # Mosaic-legal lane block
         assert (o + pad) // b <= -(-o // b)      # never more tiles than ceil
 
     x = jax.random.normal(jax.random.PRNGKey(22), (1, 6, 6, 8))

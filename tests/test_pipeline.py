@@ -35,7 +35,8 @@ params = init(cfg, jax.random.PRNGKey(0))
 unit, reps, rest = layer_plan(cfg)
 assert reps == 8 and not rest
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 res = {}
 for M in (6, 2):       # M=2 < S=4: the pipe never fully fills
     mb, S, D = 2, 16, cfg.d_model

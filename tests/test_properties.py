@@ -140,14 +140,20 @@ def test_compressed_psum_errorbound(bits, seed):
        bkw=st.one_of(st.none(), st.integers(1, 512)))
 def test_autotune_tile_requests_always_legal(m, n, kw, ab, wb, bm, bn, bkw):
     """Any tile request — autotuner decision or caller whim — legalizes to
-    blocks the Pallas kernel's ``_check_blocks`` accepts: the tuned path
+    blocks the Pallas kernel's ``_check_blocks`` accepts on the padded
+    operands, and that Mosaic accepts on the chip (sublane dim: whole or a
+    multiple of 8; lane dims: whole or a multiple of 128): the tuned path
     can never produce an illegal BlockSpec."""
     from repro.kernels.bitserial_matmul import _check_blocks
-    from repro.kernels.ops import matmul_tiles
+    from repro.kernels.ops import matmul_tiles, padded
 
     lb, ln, lk = matmul_tiles(m, n, kw, ab, wb, bm, bn, bkw)
-    _check_blocks(m, n, kw, lb, ln, lk)    # must not raise
+    _check_blocks(padded(m, lb), padded(n, ln), padded(kw, lk),
+                  lb, ln, lk)    # must not raise
     assert 1 <= lb <= m and 1 <= ln <= n and 1 <= lk <= kw
+    assert lb == m or lb % 8 == 0
+    assert ln == n or ln % 128 == 0
+    assert lk == kw or lk % 128 == 0
 
 
 @settings(max_examples=15, deadline=None)

@@ -21,14 +21,9 @@ from repro.models.lm import abstract_params
 
 def _mesh_stub(shape, names):
     """A Mesh over 1 real device can't have size>1 — use jax.sharding.Mesh
-    abstract construction via AbstractMesh for spec-only tests.
-
-    AbstractMesh's signature changed across jax versions: 0.4.x takes one
-    ((name, size), ...) shape tuple; >=0.5 takes (sizes, names)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(shape, names)
+    abstract construction via AbstractMesh for spec-only tests."""
+    return jax.sharding.AbstractMesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def test_param_specs_dense():
@@ -125,7 +120,8 @@ from repro.models.lm.model import cast_params
 from repro.training.optimizer import OptimizerConfig, init_opt_state
 from repro.training.train_loop import make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sh.set_mesh(mesh)
 cfg = get_config("qwen3-0.6b").model.reduced(vocab=512, d_model=128)
 params = cast_params(minit(cfg, jax.random.PRNGKey(0)), jnp.bfloat16)

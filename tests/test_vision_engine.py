@@ -218,7 +218,7 @@ def test_shard_packed_conv_layout(mini_params):
     w = jax.random.normal(jax.random.PRNGKey(6), (3, 3, 16, 32))
     pk = prepack_conv(w, 4)
     pks = shard_packed(pk, mesh, axis="model", split="n")
-    assert pks.fused_planes.sharding.spec == P(None, None, "model", None, None)
+    assert pks.fused_planes.sharding.spec == P(None, None, None, None, "model")
     assert pks.mat.planes.sharding.spec == P(None, "model", None)
     assert pks.mat.codes.sharding.spec == P(None, "model")
     assert pks.mat.col_sums.sharding.spec == P("model")
@@ -241,7 +241,7 @@ def test_serve_cnn_param_shardings_rules(mini_params):
     pk = L.prepack_params(mini_params, cfg)
     shardings = sh.serve_cnn_param_shardings(pk, mesh, quantized=True)
     assert shardings["c1"]["w"].fused_planes.spec == \
-        P(None, None, "model", None, None)
+        P(None, None, None, None, "model")
     assert shardings["c1"]["w"].mat.planes.spec == P(None, "model", None)
     assert shardings["c1"]["gamma"].spec == P("model")
     assert shardings["head"]["w"].planes.spec == P(None, "model", None)
