@@ -136,6 +136,7 @@ def bitserial_matmul_packed(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((w_bits, bkw, bn), jnp.uint32)],
         interpret=interpret,
+        name="eq1_matmul_packed",
     )(pa, pw)
 
 
@@ -175,6 +176,7 @@ def bitserial_matmul_fused(
         scratch_shapes=[pltpu.VMEM((a_bits, bm, bkw), jnp.uint32),
                         pltpu.VMEM((w_bits, bkw, bn), jnp.uint32)],
         interpret=interpret,
+        name="eq1_matmul",
     )(qa, pw)
 
 

@@ -115,6 +115,7 @@ def conv2d_bitserial_fused(
     op = o + o_pad
 
     grid = (n * oh, op // bo, kh)
+    ksize = kh if kh == kw_sz else f"{kh}x{kw_sz}"
     kern = functools.partial(_kernel, a_bits=a_bits, w_bits=w_bits,
                              kw_sz=kw_sz, ow=ow, stride=stride, cw=cw)
     out = pl.pallas_call(
@@ -134,6 +135,7 @@ def conv2d_bitserial_fused(
         out_shape=jax.ShapeDtypeStruct((n * oh, ow, op), jnp.int32),
         scratch_shapes=[pltpu.VMEM((a_bits, wp, cw), jnp.uint32)],
         interpret=interpret,
+        name=f"eq1_conv_k{ksize}s{stride}",   # its op's name in a trace
     )(q, pw)
     if o_pad:
         out = out[..., :o]

@@ -156,6 +156,7 @@ def serve_gateway(args, mesh, cfg, params):
               f"tpot_p95={st['tpot_ms']['p95']} ms "
               f"max_depth={st['queue']['max_depth']}/{st['queue']['bound']} "
               f"shed_rate={st['shed_rate']:.3f}")
+        print(f"lm counters: {st['lm_counters']}")
 
     asyncio.run(run())
 

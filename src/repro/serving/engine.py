@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.lm import (
     decode_step, init_state, prefill_into_slot, prepack_params,
 )
@@ -229,6 +230,12 @@ class ServeEngine:
         self.queue: collections.deque = collections.deque()
         self.done: list = []
         self._cancelled: set = set()   # rids to release at the next boundary
+        self._queued_at: dict = {}     # rid -> obs.now() at submit, recording
+        # Work counters (stats()["counters"]): plain integer increments.
+        self.counters = dict.fromkeys(
+            ("submitted", "admitted", "prefill_chunks", "prefill_tokens",
+             "decode_dispatches", "decode_steps", "slot_steps", "tokens_out",
+             "kv_tokens"), 0)
 
         # Supervision state (inert unless watchdog/fault_injector set).
         from repro.training.fault_tolerance import (RestartPolicy,
@@ -575,7 +582,11 @@ class ServeEngine:
 
     def submit(self, req: Request):
         self.validate(req.prompt, req.max_new_tokens)
+        t = obs.now()
+        if t is not None:
+            self._queued_at[req.rid] = t
         self.queue.append(req)
+        self.counters["submitted"] += 1
 
     def cancel(self, rid: int) -> str | None:
         """Cancel a request. Queued: removed immediately. Mid-generation:
@@ -588,6 +599,7 @@ class ServeEngine:
         for i, r in enumerate(self.queue):
             if r.rid == rid:
                 del self.queue[i]
+                self._queued_at.pop(rid, None)
                 return "queued"
         for r in self.slot_req:
             if r is not None and r.rid == rid:
@@ -639,17 +651,31 @@ class ServeEngine:
             if not self.queue:
                 break
             req = self.queue.popleft()
+            t = self._queued_at.pop(req.rid, None)
+            if t is not None:
+                obs.span("serve.queued", t, rid=req.rid).close()
             prompt = np.asarray(req.prompt, np.int32)
+            chunks = _pow2_chunks(len(prompt))
             pos, logits = 0, None
-            with self._activate():
-                for c in _pow2_chunks(len(prompt)):
-                    tokens = jnp.asarray(prompt[pos:pos + c], jnp.int32)[None]
-                    logits, self.state = self._prefill(
-                        self.params, self.state, tokens, slot, pos)
-                    pos += c
-                self.ctrl, tok = self._admit_ctrl(
-                    self.ctrl, logits, slot, req.eos_id, req.max_new_tokens)
-            first = int(tok)
+            with obs.span("serve.admit", rid=req.rid, prompt_len=len(prompt),
+                          chunks=len(chunks),
+                          live=sum(r is not None for r in self.slot_req)):
+                with self._activate():
+                    for c in chunks:
+                        tokens = jnp.asarray(prompt[pos:pos + c],
+                                             jnp.int32)[None]
+                        logits, self.state = self._prefill(
+                            self.params, self.state, tokens, slot, pos)
+                        pos += c
+                    self.ctrl, tok = self._admit_ctrl(
+                        self.ctrl, logits, slot, req.eos_id,
+                        req.max_new_tokens)
+                first = int(tok)
+            ctr = self.counters
+            ctr["admitted"] += 1
+            ctr["prefill_chunks"] += len(chunks)
+            ctr["prefill_tokens"] += len(prompt)
+            ctr["tokens_out"] += 1
             self.slot_out[slot] = [first]
             if req.max_new_tokens <= 1 or first == req.eos_id:
                 self.done.append(Completion(req.rid, self.slot_out[slot]))
@@ -666,13 +692,15 @@ class ServeEngine:
         rollback + backoff retry on failure and degradation to the float
         path once the failure budget is spent (see :meth:`_step_supervised`).
         """
-        if self._cancelled:
-            # Before the supervised shadow: a rollback must not resurrect a
-            # cancelled request (the shadow then captures post-cancel state).
-            self._release_cancelled()
-        if self.watchdog is None and self.fault_injector is None:
-            return self._step_once()
-        return self._step_supervised()
+        with obs.span("serve.step"):
+            if self._cancelled:
+                # Before the supervised shadow: a rollback must not resurrect
+                # a cancelled request (the shadow then captures post-cancel
+                # state).
+                self._release_cancelled()
+            if self.watchdog is None and self.fault_injector is None:
+                return self._step_once()
+            return self._step_supervised()
 
     def _step_once(self) -> list:
         """One unsupervised dispatch (the pre-watchdog ``step()`` body)."""
@@ -696,11 +724,20 @@ class ServeEngine:
                 self.rings["moe_drop_frac"].push(float(v))
         if self._transient:
             self._last_ok = bool(res.pop(0))
-        toks = np.asarray(toks)
+        with obs.span("serve.fetch"):
+            toks = np.asarray(toks)
         dones = np.asarray(dones)
+        ctr = self.counters
+        ctr["decode_dispatches"] += 1
+        ctr["decode_steps"] += n
+        ctr["slot_steps"] += n * len(live)
         for k in range(n):
             for i in list(live):
                 req = self.slot_req[i]
+                # The token decoded from the slot's last one attends the
+                # prompt and every output token so far.
+                ctr["kv_tokens"] += len(req.prompt) + len(self.slot_out[i])
+                ctr["tokens_out"] += 1
                 self.slot_out[i].append(int(toks[k, i]))
                 self.slot_remaining[i] -= 1
                 if dones[k, i]:
@@ -714,12 +751,15 @@ class ServeEngine:
         return out
 
     def stats(self) -> dict:
-        """Live telemetry snapshot: supervision health plus the ring-buffer
-        channels (MoE engines: ``moe_drop_frac`` — per-decode-step fraction
-        of top-k routing assignments dropped at expert capacity). The
-        gateway merges this into its own :meth:`Gateway.stats` payload so
-        operators see routing overflow next to goodput/shed counts."""
-        out = {"health": dict(self.health)}
+        """Live telemetry snapshot: supervision health, the work counters
+        (requests submitted and admitted, prefill chunks and tokens, decode
+        dispatches and steps, live slots x steps, output tokens, and the
+        context length attended summed over decoded tokens), plus the
+        ring-buffer channels (MoE engines: ``moe_drop_frac`` — per-decode-
+        step fraction of top-k routing assignments dropped at expert
+        capacity). :meth:`Gateway.stats` carries the health and the
+        counters as ``lm_health`` / ``lm_counters``."""
+        out = {"health": dict(self.health), "counters": dict(self.counters)}
         for name, ring in self.rings.items():
             v = ring.values()
             out[name] = dict(ring.percentiles(),

@@ -846,8 +846,9 @@ class Gateway:
                 "events": list(self._events),
                 "errors": list(self._errors),
             }
-        if self._lm is not None:
-            snapshot["lm_health"] = dict(self._lm.health)
-        if self._vision is not None:
-            snapshot["vision_health"] = dict(self._vision.health)
+        for name, eng in (("lm", self._lm), ("vision", self._vision)):
+            if eng is not None:
+                st = eng.stats()
+                snapshot[f"{name}_health"] = st["health"]
+                snapshot[f"{name}_counters"] = st["counters"]
         return snapshot

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import PIMQuantConfig
 from repro.models.cnn import alexnet, resnet, vgg
 from repro.models.cnn.layers import prepack_params as _prepack_cnn
@@ -178,6 +179,9 @@ class VisionEngine:
         self._fault_key = jax.random.PRNGKey(seed)
         self.health = {"dispatches": 0, "rollbacks": 0, "repairs": 0,
                        "repaired_cols": 0, "degraded": []}
+        # Work counters (stats()["counters"]): launches, images, and
+        # launches per bucket size.
+        self.counters = {"dispatches": 0, "images": 0, "by_bucket": {}}
         # Lint-gate registration (repro.analysis; DESIGN.md §10). Image
         # shapes are only known at dispatch, so _dispatch records each
         # (model, precision, bucket) -> image shape for hot_paths().
@@ -434,6 +438,14 @@ class VisionEngine:
                 context=_partial(self._activate, quantized)))
         return out
 
+    def stats(self) -> dict:
+        """Live telemetry snapshot: supervision health and the work
+        counters. :meth:`Gateway.stats` carries them as ``vision_health`` /
+        ``vision_counters``."""
+        return {"health": dict(self.health),
+                "counters": dict(self.counters,
+                                 by_bucket=dict(self.counters["by_bucket"]))}
+
     def close(self):
         """Engine teardown: deregister from the lint gate and reset the
         tuning cache (see ServeEngine.close)."""
@@ -503,33 +515,41 @@ class VisionEngine:
         """
         if not self.queue:
             return []
-        key = self._group_key(self.queue[0])
-        # Two O(Q) passes, no per-request deque.remove: size the cohort,
-        # then split taken / kept preserving the queue order of the rest.
-        m = 0
-        for r in self.queue:
-            if self._group_key(r) == key:
-                m += 1
-                if m == self.max_batch:
-                    break
-        bucket = 1 << (m.bit_length() - 1)
-        group, kept = [], []
-        for r in self.queue:
-            if len(group) < bucket and self._group_key(r) == key:
-                group.append(r)
-            else:
-                kept.append(r)
-        self.queue = collections.deque(kept)
-        model, precision, _ = key
-        if (model, precision) in self._degraded:
-            # Degraded cohort: serve on the float fallback path (completions
-            # keep their original rids; only the numerics path changes).
-            precision = None
-        if self.watchdog is None and self.fault_injector is None:
-            return self._dispatch(group, model, precision)
-        return self._dispatch_supervised(group, model, precision)
+        with obs.span("vision.step"):
+            t0 = obs.now()   # vision.prepare: cohort pick to launch
+            key = self._group_key(self.queue[0])
+            # Two O(Q) passes, no per-request deque.remove: size the cohort,
+            # then split taken / kept preserving the queue order of the rest.
+            m = 0
+            for r in self.queue:
+                if self._group_key(r) == key:
+                    m += 1
+                    if m == self.max_batch:
+                        break
+            bucket = 1 << (m.bit_length() - 1)
+            group, kept = [], []
+            for r in self.queue:
+                if len(group) < bucket and self._group_key(r) == key:
+                    group.append(r)
+                else:
+                    kept.append(r)
+            self.queue = collections.deque(kept)
+            model, precision, _ = key
+            if (model, precision) in self._degraded:
+                # Degraded cohort: serve on the float fallback path
+                # (completions keep their original rids; only the numerics
+                # path changes).
+                precision = None
+            if self.watchdog is None and self.fault_injector is None:
+                return self._dispatch(group, model, precision, t0)
+            return self._dispatch_supervised(group, model, precision)
 
-    def _dispatch(self, group, model: str, precision: str | None) -> list:
+    def _dispatch(self, group, model: str, precision: str | None,
+                  t0: int | None = None) -> list:
+        """Stack, copy and launch one bucket; ``t0`` (an ``obs.now()``)
+        opens its ``vision.prepare`` span earlier than here."""
+        if t0 is None:
+            t0 = obs.now()
         bucket = len(group)
         batch = jnp.asarray(
             np.stack([np.asarray(r.image, np.float32) for r in group]))
@@ -550,10 +570,17 @@ class VisionEngine:
                 params if quantized and self.autotune != "off" else None)
             if quantized and self._transient:
                 self._fault_key, dkey = jax.random.split(self._fault_key)
-                logits = fn(params, batch, dkey)
+                args = (params, batch, dkey)
             else:
-                logits = fn(params, batch)
-        logits = np.asarray(logits)
+                args = (params, batch)
+            obs.span("vision.prepare", t0).close()
+            logits = fn(*args)
+        ctr = self.counters
+        ctr["dispatches"] += 1
+        ctr["images"] += bucket
+        ctr["by_bucket"][bucket] = ctr["by_bucket"].get(bucket, 0) + 1
+        with obs.span("vision.fetch"):
+            logits = np.asarray(logits)
         return [
             VisionCompletion(rid=r.rid, logits=logits[i],
                              top1=int(logits[i].argmax()), batch=bucket)

@@ -401,8 +401,9 @@ def test_stats_snapshot_shape(params):
     st = asyncio.run(main())
     for key in ("tier", "queue", "ttft_ms", "ttft_admit_ms", "tpot_ms",
                 "tok_s", "shed", "shed_rate", "goodput_tok_s_by_tenant",
-                "events", "errors", "lm_health"):
+                "events", "errors", "lm_health", "lm_counters"):
         assert key in st, key
+    assert st["lm_counters"]["admitted"] == st["lm_counters"]["submitted"] == 1
     assert st["queue"]["bound"] > 0
     assert "acme" in st["goodput_tok_s_by_tenant"]
     assert st["shed_rate"] == 0.0
