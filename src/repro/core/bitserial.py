@@ -133,7 +133,8 @@ def int_matmul(qa, qw, a_bits, w_bits, backend="popcount"):
 
 
 def int_matmul_prepacked(qa: jax.Array, w: PackedWeight, a_bits: int,
-                         backend: str = "popcount") -> jax.Array:
+                         backend: str = "popcount",
+                         name: str = "eq1_matmul") -> jax.Array:
     """P = qa @ w.codes using whatever representation the backend wants.
 
     The popcount/pallas backends consume the prepacked planes directly —
@@ -149,6 +150,8 @@ def int_matmul_prepacked(qa: jax.Array, w: PackedWeight, a_bits: int,
     (``w.tune``, see :mod:`repro.pim.autotune`) overrides ``backend`` and
     supplies Pallas tile requests — tuning redirects dispatch only; every
     backend computes the same P bit-exactly, so the result is invariant.
+
+    ``name`` names the Pallas kernel in a trace; the XLA backends ignore it.
     """
     from repro.pim import faults as _faults  # lazy: pim imports core
 
@@ -170,7 +173,7 @@ def int_matmul_prepacked(qa: jax.Array, w: PackedWeight, a_bits: int,
         tiles = {} if tune is None else dict(bm=tune.bm, bn=tune.bn,
                                              bkw=tune.bkw)
         return _kops.bitserial_matmul(qa, a_bits=a_bits, w_bits=w.bits,
-                                      pw=w.planes, **tiles)
+                                      pw=w.planes, name=name, **tiles)
     raise ValueError(f"unknown backend {backend!r}")
 
 

@@ -15,15 +15,17 @@ deployment by :func:`prepack_linear`/:func:`prepack_conv2d` — the paper's
 "program subarrays once" step. See DESIGN.md §3.
 
 Conv2D lowers to the same integer matmul two ways: a materialized im2col
-patch matrix (cheap for 1x1 kernels and small maps), or the fused
-implicit-im2col Pallas kernel that walks patch offsets inside the grid and
-never builds the (N*OH*OW, KH*KW*C) matrix — exactly how the paper slides
-the weight buffer over resident input planes (Fig. 8). The choice is a
-shape-dispatch heuristic (:func:`fuse_conv_heuristic`) or forced via
-``conv_mode``.
+patch matrix (cheap for 1x1 kernels, small maps, and inputs with few
+channels), or the fused implicit-im2col Pallas kernel that walks patch
+offsets inside the grid and never builds the (N*OH*OW, KH*KW*C) matrix —
+exactly how the paper slides the weight buffer over resident input planes
+(Fig. 8). The choice is a shape-dispatch heuristic
+(:func:`fuse_conv_heuristic`) or forced via ``conv_mode``.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 
 import jax
@@ -119,15 +121,26 @@ def pim_linear(
 
 def _im2col(x: jax.Array, kh: int, kw: int, stride: int, padding: int
             ) -> tuple[jax.Array, int, int]:
-    """NHWC -> (N*OH*OW, KH*KW*C) patches (float x or integer codes)."""
+    """NHWC -> (N*OH*OW, KH*KW*C) patches (float x or integer codes).
+
+    Built from KH*KW slices of the padded map, one per tap, concatenated
+    on the channel axis in (kh, kw, c) order: a copy at memory bandwidth,
+    where indexing by patch offsets lowers to a gather. A strided conv
+    first splits the map into its stride x stride phases (one strided
+    slice each), so that each tap is a unit-stride slice of one phase.
+    """
     n, h, w, c = x.shape
     x = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
     oh = (h + 2 * padding - kh) // stride + 1
     ow = (w + 2 * padding - kw) // stride + 1
-    idx_h = stride * jnp.arange(oh)[:, None] + jnp.arange(kh)[None, :]
-    idx_w = stride * jnp.arange(ow)[:, None] + jnp.arange(kw)[None, :]
-    patches = x[:, idx_h[:, None, :, None], idx_w[None, :, None, :], :]
-    # (n, oh, ow, kh, kw, c) -> (n*oh*ow, kh*kw*c)
+    phases = {(a, b): jax.lax.slice(x, (0, a, b, 0), x.shape,
+                                    (1, stride, stride, 1))
+              for a in range(min(kh, stride)) for b in range(min(kw, stride))}
+    taps = [jax.lax.slice(phases[i % stride, j % stride],
+                          (0, i // stride, j // stride, 0),
+                          (n, i // stride + oh, j // stride + ow, c))
+            for i in range(kh) for j in range(kw)]
+    patches = jnp.concatenate(taps, axis=-1)
     return patches.reshape(n * oh * ow, kh * kw * c), oh, ow
 
 
@@ -136,21 +149,57 @@ def _im2col(x: jax.Array, kh: int, kw: int, stride: int, padding: int
 _FUSE_MIN_BYTES = 4 << 20
 
 
+def sparse_channel_words(kh: int, kw: int, c: int) -> bool:
+    """Does the fused kernel issue more than twice the packed words of the
+    im2col GEMM? It packs only channels into a 32-bit word, KH*KW*ceil(C/32)
+    words per output and plane pair, where the GEMM packs the whole patch
+    into ceil(KH*KW*C/32). Never true for C >= 32."""
+    return kh * kw * -(-c // 32) > 2 * -(-(kh * kw * c) // 32)
+
+
 def fuse_conv_heuristic(n: int, oh: int, ow: int, kh: int, kw: int, c: int,
                         backend: str) -> bool:
     """Should ``pim_conv2d`` take the fused implicit-im2col path?
 
     Fused pays when (a) the backend runs the paper dataflow on the Pallas
     kernels (the fused kernel *is* that dataflow; the XLA backends have no
-    kernel to fuse into) and (b) the materialized (N*OH*OW, KH*KW*C) patch
+    kernel to fuse into), (b) the materialized (N*OH*OW, KH*KW*C) patch
     matrix is a real HBM blow-up — 1x1 kernels materialize for free (the
-    patch matrix is a reshape) and tiny maps fit in cache anyway.
+    patch matrix is a reshape) and tiny maps fit in cache anyway — and (c)
+    its channel words are mostly full (:func:`sparse_channel_words`). An
+    input with few channels (a first conv on RGB: ResNet's 7x7 stem, 49
+    words against the im2col GEMM's 5) leaves most of each fused word
+    empty, and past twice the im2col count the fused path stops paying.
     """
     if backend != "pallas":
         return False
     if kh == kw == 1:
         return False
+    if sparse_channel_words(kh, kw, c):
+        return False
     return 4 * n * oh * ow * kh * kw * c >= _FUSE_MIN_BYTES
+
+
+# Conv lowerings counted while a forward is traced (``conv_lowering_log``).
+_LOWERING_LOGS: list = []
+LOWERINGS = ("fused", "im2col", "pointwise")
+
+
+@contextlib.contextmanager
+def conv_lowering_log():
+    """Count the quantized convs ``pim_conv2d`` lowers inside the block, by
+    path: ``fused``, ``im2col`` (KH*KW > 1) and ``pointwise`` (1x1).
+
+    Counted on the host while a conv is traced, so a jitted forward counts
+    on the call that traces it and not on later calls; nothing reaches the
+    device. Yields a ``collections.Counter``.
+    """
+    log = collections.Counter()
+    _LOWERING_LOGS.append(log)
+    try:
+        yield log
+    finally:
+        _LOWERING_LOGS.remove(log)
 
 
 def pim_conv2d(
@@ -223,8 +272,18 @@ def pim_conv2d(
                                    bo=tune.bo if tune is not None else None)
     else:
         qcols, _, _ = _im2col(qx, kh, kw, stride, 0)
-        p = int_matmul_prepacked(qcols, w.mat, cfg.a_bits, cfg.backend)
+        # A KxK conv's GEMM takes the fused kernel's name, so the trace
+        # shows each conv under one name whichever path it takes.
+        from repro.kernels.conv2d_fused import kernel_name
+
+        name = "eq1_matmul" if kh == kw == 1 else kernel_name(kh, kw, stride)
+        p = int_matmul_prepacked(qcols, w.mat, cfg.a_bits, cfg.backend,
+                                 name=name)
         p = p.reshape(n, oh, ow, o)
+    lowering = ("fused" if fused else "pointwise" if kh == kw == 1
+                else "im2col")
+    for log in _LOWERING_LOGS:
+        log[lowering] += 1
     # Patch-wise activation code sums for the affine correction: a strided
     # box sum over the per-pixel channel sums — no patch matrix needed.
     sa = jax.lax.reduce_window(

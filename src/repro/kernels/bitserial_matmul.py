@@ -141,8 +141,9 @@ def bitserial_matmul_packed(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("a_bits", "w_bits", "bm", "bn", "bkw", "interpret")
-)
+    jax.jit,
+    static_argnames=("a_bits", "w_bits", "bm", "bn", "bkw", "interpret",
+                     "name"))
 def bitserial_matmul_fused(
     qa: jax.Array,  # (M, K) int32 activation codes, K % 32 == 0
     pw: jax.Array,  # (w_bits, N, K//32) uint32 prepacked weight planes
@@ -153,8 +154,12 @@ def bitserial_matmul_fused(
     bn: int = 128,
     bkw: int = 64,
     interpret: bool = False,
+    name: str = "eq1_matmul",
 ) -> jax.Array:
-    """Fused pack+matmul: activation codes in, (M, N) int32 out, one launch."""
+    """Fused pack+matmul: activation codes in, (M, N) int32 out, one launch.
+
+    ``name`` is the kernel's name in a trace (a conv's GEMM takes its
+    conv's name)."""
     m, k = qa.shape
     _, n, kw = pw.shape
     if k != kw * 32:
@@ -176,7 +181,7 @@ def bitserial_matmul_fused(
         scratch_shapes=[pltpu.VMEM((a_bits, bm, bkw), jnp.uint32),
                         pltpu.VMEM((w_bits, bkw, bn), jnp.uint32)],
         interpret=interpret,
-        name="eq1_matmul",
+        name=name,
     )(qa, pw)
 
 

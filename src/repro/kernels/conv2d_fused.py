@@ -27,6 +27,13 @@ size-1 block on the row axis, so the index map addresses the *element* row
 im2col. Inside a step, each channel word is a (OW, 1) activation column
 broadcast across lanes, AND-ed with a (1, bo) weight row broadcast across
 sublanes, as in :mod:`.bitserial_matmul`.
+
+Where it stops paying: only channels share a 32-bit word, so each output
+and plane pair costs KH*KW*CW words. An input with few channels leaves
+most of each word empty — ResNet's 7x7 stem on C = 3 fills 3 bits of 32,
+49 words where the im2col GEMM packs the same 147-element patch into 5.
+:func:`repro.core.pim_layers.fuse_conv_heuristic` sends such convs to the
+im2col GEMM (:mod:`.bitserial_matmul`) instead.
 """
 from __future__ import annotations
 
@@ -55,6 +62,12 @@ def _pad_o_blocks(o: int, bo: int) -> tuple[int, int]:
         return o, 0
     bo = max(128, bo // 128 * 128)
     return bo, -o % bo
+
+
+def kernel_name(kh: int, kw: int, stride: int) -> str:
+    """The name a KHxKW/stride conv's Eq. 1 kernel carries in a trace."""
+    ksize = kh if kh == kw else f"{kh}x{kw}"
+    return f"eq1_conv_k{ksize}s{stride}"
 
 
 def _kernel(q_ref, w_ref, o_ref, a_ref, *, a_bits: int, w_bits: int,
@@ -115,7 +128,6 @@ def conv2d_bitserial_fused(
     op = o + o_pad
 
     grid = (n * oh, op // bo, kh)
-    ksize = kh if kh == kw_sz else f"{kh}x{kw_sz}"
     kern = functools.partial(_kernel, a_bits=a_bits, w_bits=w_bits,
                              kw_sz=kw_sz, ow=ow, stride=stride, cw=cw)
     out = pl.pallas_call(
@@ -135,7 +147,7 @@ def conv2d_bitserial_fused(
         out_shape=jax.ShapeDtypeStruct((n * oh, ow, op), jnp.int32),
         scratch_shapes=[pltpu.VMEM((a_bits, wp, cw), jnp.uint32)],
         interpret=interpret,
-        name=f"eq1_conv_k{ksize}s{stride}",   # its op's name in a trace
+        name=kernel_name(kh, kw_sz, stride),   # its op's name in a trace
     )(q, pw)
     if o_pad:
         out = out[..., :o]

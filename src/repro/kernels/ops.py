@@ -105,6 +105,7 @@ def bitserial_matmul(
     bn: int | None = None,
     bkw: int | None = None,
     interpret: bool | None = None,
+    name: str = "eq1_matmul",
 ) -> jax.Array:
     """Eq. 1 bit-serial integer matmul via the Pallas kernels -> (M, N) i32.
 
@@ -112,7 +113,7 @@ def bitserial_matmul(
     prepacked weight planes of a ``PackedWeight``) to make the whole product
     a single ``pallas_call``. ``bm``/``bn``/``bkw`` override the planner's
     tile choices (see :func:`matmul_tiles`); the autotuner threads its
-    decisions through here.
+    decisions through here. ``name`` names the kernel in a trace.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -132,7 +133,7 @@ def bitserial_matmul(
     pw = jnp.pad(pw, ((0, 0), (0, padded(n, bn) - n), (0, kwp - kw)))
     return _bsm.bitserial_matmul_fused(
         qa, pw, a_bits=a_bits, w_bits=w_bits, bm=bm, bn=bn, bkw=bkw,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )[:m, :n]
 
 

@@ -49,6 +49,7 @@ import warnings
 
 from repro.core.packed import (PackedConvWeight, PackedWeight, TuneDecision,
                                prepack)
+from repro.core.pim_layers import sparse_channel_words
 from repro.models.cnn.specs import GemmSpec
 
 from .cost_model import CostModel
@@ -385,7 +386,12 @@ def decide_conv(n: int, h: int, w: int, c: int, o: int, kh: int, kw: int,
     as the underlying GEMM plus the patch-matrix bus traffic the paper's
     fused schedule never pays — zero for 1x1 kernels, where im2col is a
     reshape), and the fused implicit-im2col kernel per O-block when
-    "pallas" is allowed.
+    "pallas" is allowed. The fused kernel is no candidate where its
+    channel words are mostly empty (:func:`repro.core.pim_layers.
+    sparse_channel_words`, the rule :func:`fuse_conv_heuristic` applies):
+    the mapper prices the dense GEMM either way and charges only the
+    im2col path for streaming its input, so it cannot see the empty
+    words (ResNet's 7x7 stem on C = 3: 49 words, 5 for the im2col GEMM).
     """
     if mode not in ("cost", "measure"):
         raise ValueError(f"autotune mode {mode!r}: want 'cost' | 'measure'")
@@ -417,6 +423,7 @@ def decide_conv(n: int, h: int, w: int, c: int, o: int, kh: int, kw: int,
         d = TuneDecision(backend="pallas", conv_mode="im2col")
         scored.append((analytic_gemm_cost(m, kdim, o, a_bits, w_bits, d)
                        + patch_t, len(backends), d))
+    if "pallas" in backends and not sparse_channel_words(kh, kw, c):
         base = _price(spec, a_bits, w_bits) / _rates()["pallas"]
         for j, bo in enumerate((64, 128, 256)):
             steps = math.ceil(o / min(bo, o))

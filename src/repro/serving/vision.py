@@ -66,6 +66,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import PIMQuantConfig
+from repro.core.pim_layers import LOWERINGS, conv_lowering_log
 from repro.models.cnn import alexnet, resnet, vgg
 from repro.models.cnn.layers import prepack_params as _prepack_cnn
 
@@ -179,9 +180,12 @@ class VisionEngine:
         self._fault_key = jax.random.PRNGKey(seed)
         self.health = {"dispatches": 0, "rollbacks": 0, "repairs": 0,
                        "repaired_cols": 0, "degraded": []}
-        # Work counters (stats()["counters"]): launches, images, and
-        # launches per bucket size.
-        self.counters = {"dispatches": 0, "images": 0, "by_bucket": {}}
+        # Work counters (stats()["counters"]): launches, images, launches
+        # per bucket size, and the convs of the compiled quantized forwards
+        # by lowering (pim_layers.LOWERINGS), each forward counted once, as
+        # it is traced.
+        self.counters = {"dispatches": 0, "images": 0, "by_bucket": {},
+                         "conv_lowering": dict.fromkeys(LOWERINGS, 0)}
         # Lint-gate registration (repro.analysis; DESIGN.md §10). Image
         # shapes are only known at dispatch, so _dispatch records each
         # (model, precision, bucket) -> image shape for hot_paths().
@@ -442,9 +446,10 @@ class VisionEngine:
         """Live telemetry snapshot: supervision health and the work
         counters. :meth:`Gateway.stats` carries them as ``vision_health`` /
         ``vision_counters``."""
+        ctr = self.counters
         return {"health": dict(self.health),
-                "counters": dict(self.counters,
-                                 by_bucket=dict(self.counters["by_bucket"]))}
+                "counters": dict(ctr, by_bucket=dict(ctr["by_bucket"]),
+                                 conv_lowering=dict(ctr["conv_lowering"]))}
 
     def close(self):
         """Engine teardown: deregister from the lint gate and reset the
@@ -574,8 +579,11 @@ class VisionEngine:
             else:
                 args = (params, batch)
             obs.span("vision.prepare", t0).close()
-            logits = fn(*args)
+            with conv_lowering_log() as lowered:
+                logits = fn(*args)
         ctr = self.counters
+        for k in LOWERINGS:
+            ctr["conv_lowering"][k] += lowered[k]
         ctr["dispatches"] += 1
         ctr["images"] += bucket
         ctr["by_bucket"][bucket] = ctr["by_bucket"].get(bucket, 0) + 1

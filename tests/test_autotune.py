@@ -278,6 +278,20 @@ def test_serve_engine_snapshot_carries_tuning(tmp_path):
     assert len(eng2.tune_cache) >= len(eng.tune_cache)
 
 
+@pytest.mark.parametrize("backends", [("pallas",), at.ALL_BACKENDS])
+def test_stem_conv_tuned_to_im2col(backends):
+    """ResNet's 7x7/2 stem on C = 3 channels: the fused kernel would fill 3
+    bits of each 32-bit word (49 words against the im2col GEMM's 5), so it
+    is no candidate and the tuner picks the im2col path, as
+    fuse_conv_heuristic does; a 3x3 on C = 64 may still fuse."""
+    conv, _ = at.decide_conv(16, 224, 224, 3, 64, 7, 7, stride=2, padding=3,
+                             backends=backends)
+    assert conv.conv_mode == "im2col"
+    conv, _ = at.decide_conv(16, 56, 56, 64, 64, 3, 3, stride=1, padding=1,
+                             backends=("pallas",))
+    assert conv.conv_mode == "fused"
+
+
 def test_vision_engine_autotune_parity(tmp_path):
     from repro.models.cnn import alexnet
     from repro.serving.vision import VisionEngine, VisionRequest

@@ -212,12 +212,15 @@ def test_conv_activation_calibration_ignores_padding():
     """Regression: activation quantization used to calibrate on the padded
     tensor, so a strictly-positive input range (post-ReLU features) was
     stretched down to the padding zeros — wasted code space, inflated
-    error. Calibrating on the real input must beat the old behavior."""
+    error. Calibrating on the real input must beat the old behavior.
+
+    8-bit weights with 4-bit activations, so the activation calibration's
+    error sets the max (at 4-bit weights their error ties the two)."""
     key = jax.random.PRNGKey(24)
     # post-ReLU-like features in [2, 5]: zero is far outside the range
     x = jax.random.uniform(key, (2, 8, 8, 16), minval=2.0, maxval=5.0)
     w = jax.random.normal(jax.random.PRNGKey(25), (3, 3, 16, 8)) * 0.1
-    cfg = PIMQuantConfig(w_bits=4, a_bits=4, backend="int-direct")
+    cfg = PIMQuantConfig(w_bits=8, a_bits=4, backend="int-direct")
     ref = jax.lax.conv_general_dilated(
         x, w, (1, 1), [(1, 1)] * 2,
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
@@ -253,6 +256,96 @@ def test_fuse_heuristic_dispatch():
     assert not fuse_conv_heuristic(64, 112, 112, 1, 1, 64, "pallas")
     assert not fuse_conv_heuristic(64, 112, 112, 3, 3, 64, "int-direct")
     assert not fuse_conv_heuristic(1, 4, 4, 3, 3, 8, "pallas")  # tiny map
+
+
+@pytest.mark.parametrize("shape,fused", [
+    ((16, 112, 112, 7, 7, 3), False),   # ResNet's 224-px stem: 49 vs 5 words
+    ((16, 55, 55, 11, 11, 3), False),   # AlexNet's 11x11/4: 121 vs 12
+    ((16, 56, 56, 3, 3, 64), True),     # full channel words: 18 vs 18
+    ((16, 56, 56, 3, 3, 48), True),     # half-empty second word: 18 vs 14
+])
+def test_fuse_heuristic_word_fill(shape, fused):
+    """Convs whose channel words are mostly empty take the im2col GEMM:
+    the fused kernel would issue more than twice its packed words."""
+    assert fuse_conv_heuristic(*shape, "pallas") is fused
+
+
+def test_stem_geometry_lowerings_bit_identical(monkeypatch):
+    """The stem's 7x7/2, C = 3 conv on a small map: fused, im2col and auto
+    give the same P-derived outputs, and auto takes the im2col GEMM by the
+    word-fill rule alone (the size rule is switched off), its kernel named
+    as the fused one would be."""
+    from repro.core import pim_layers
+
+    monkeypatch.setattr(pim_layers, "_FUSE_MIN_BYTES", 0)
+    x = jax.random.normal(jax.random.PRNGKey(40), (1, 32, 32, 3))
+    w = jax.random.normal(jax.random.PRNGKey(41), (7, 7, 3, 64)) * 0.1
+    cfg = PIMQuantConfig(w_bits=8, a_bits=8, backend="pallas")
+    pk = prepack_conv2d(w, cfg)
+
+    def conv(mode):
+        return lambda x: pim_conv2d(x, pk, stride=2, padding=3, cfg=cfg,
+                                    conv_mode=mode)
+
+    outs = {m: conv(m)(x) for m in ("fused", "im2col", "auto")}
+    assert jnp.array_equal(outs["fused"], outs["im2col"])
+    assert jnp.array_equal(outs["auto"], outs["im2col"])
+    with pim_layers.conv_lowering_log() as log:
+        text = str(jax.make_jaxpr(conv("auto"))(x))
+    assert dict(log) == {"im2col": 1}
+    assert "name=eq1_conv_k7s2" in text
+    assert "eq1_matmul" not in text
+    # the same name as the fused path's kernel
+    assert "eq1_conv_k7s2" in str(jax.make_jaxpr(conv("fused"))(x))
+
+
+def _im2col_gather(x, kh, kw, stride, padding):
+    """The patch build by patch-offset indexing (a gather), kept as the
+    reference of the slice-built ``_im2col``."""
+    n, h, w, c = x.shape
+    x = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
+    oh = (h + 2 * padding - kh) // stride + 1
+    ow = (w + 2 * padding - kw) // stride + 1
+    idx_h = stride * jnp.arange(oh)[:, None] + jnp.arange(kh)[None, :]
+    idx_w = stride * jnp.arange(ow)[:, None] + jnp.arange(kw)[None, :]
+    patches = x[:, idx_h[:, None, :, None], idx_w[None, :, None, :], :]
+    return patches.reshape(n * oh * ow, kh * kw * c), oh, ow
+
+
+@pytest.mark.parametrize("k,stride,padding", [
+    (1, 1, 0), (1, 2, 0), (3, 2, 1), (7, 2, 3)])
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_im2col_slices_match_gather(k, stride, padding, dtype):
+    """The slice-built patch matrix equals the gather-built one, element
+    for element, at odd widths (a stride that does not divide the map)."""
+    from repro.core.pim_layers import _im2col
+
+    x = jax.random.randint(jax.random.PRNGKey(42), (2, 13, 11, 3), -128,
+                           128).astype(dtype)
+    got, oh, ow = _im2col(x, k, k, stride, padding)
+    want, oh_r, ow_r = _im2col_gather(x, k, k, stride, padding)
+    assert (oh, ow) == (oh_r, ow_r)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert jnp.array_equal(got, want)
+    jaxpr = str(jax.make_jaxpr(lambda x: _im2col(x, k, k, stride,
+                                                 padding)[0])(x))
+    assert "gather" not in jaxpr
+
+
+def test_resnet50_224_conv_lowering_counts():
+    """ResNet-50 at 224 px on the Pallas backend lowers 16 convs fused
+    (the 3x3s), one through the im2col GEMM (the 7x7 stem) and 36
+    pointwise; counted as the forward is traced, without running it."""
+    from repro.core import pim_layers
+    from repro.models.cnn import resnet
+
+    cfg = PIMQuantConfig(w_bits=8, a_bits=8, backend="pallas")
+    params = jax.eval_shape(lambda: resnet.prepack(
+        resnet.init(jax.random.PRNGKey(0), 1000, 224), cfg))
+    x = jax.ShapeDtypeStruct((16, 224, 224, 3), jnp.float32)
+    with pim_layers.conv_lowering_log() as log:
+        jax.eval_shape(lambda p, x: resnet.apply(p, x, cfg=cfg), params, x)
+    assert dict(log) == {"fused": 16, "im2col": 1, "pointwise": 36}
 
 
 # ---------------------------------------------------------------------------

@@ -341,6 +341,9 @@ def test_vision_gateway_roundtrip_matches_engine():
         st = gw.stats()
         gw.stop()
         assert st["ttft_ms"]["p50"] is not None
+        lowered = st["vision_counters"]["conv_lowering"]
+        assert lowered["im2col"] >= 1       # one per compiled bucket
+        assert lowered["fused"] == lowered["pointwise"] == 0
         return {c.rid: (c.top1, c.logits) for c in outs}
 
     got = asyncio.run(main())

@@ -136,6 +136,28 @@ def test_pow2_bucketing_and_bounded_compiles(mini_params):
     assert sorted(b for (_, _, b) in eng._fwd) == [2, 4]
 
 
+def test_conv_lowering_counted_per_compiled_forward(mini_params):
+    """stats()["counters"]["conv_lowering"] counts the convs of each
+    compiled quantized forward by lowering, once, as the forward is traced:
+    later dispatches of the same program add nothing, and float forwards
+    lower no quantized conv."""
+    eng = VisionEngine({"mini": (MINI, mini_params)}, max_batch=4)
+    imgs = _images(6, seed=3)
+    for rnd in range(2):       # the second round reuses both programs
+        for i in range(6):
+            eng.submit(VisionRequest(rid=10 * rnd + i, image=imgs[i],
+                                     model="mini", precision="<4:4>"))
+        eng.submit(VisionRequest(rid=100 + rnd, image=imgs[0], model="mini",
+                                 precision=None))
+        eng.run()
+        # buckets of 4 and 2, each compiled once: two convs apiece
+        want = {"fused": 0, "im2col": 4, "pointwise": 0}
+        ctr = eng.stats()["counters"]
+        assert ctr["conv_lowering"] == want
+    ctr["conv_lowering"]["im2col"] = 0                     # a copy
+    assert eng.stats()["counters"]["conv_lowering"] == want
+
+
 def test_mixed_precision_cohorts_group_separately(mini_params):
     """Interleaved precisions serve in per-(model, precision) buckets."""
     eng = VisionEngine({"mini": (MINI, mini_params)}, max_batch=8)
